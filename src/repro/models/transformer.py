@@ -173,16 +173,18 @@ def build(cfg: ModelConfig, mesh, rules, *, remat: str = "full",
             pos = jnp.arange(S)[None, :]
             q = rope(q, pos, cfg.rope_theta)
             k = rope(k, pos, cfg.rope_theta)
-            if cp:
-                o = _cp_attention(q, k, v, window)
-            else:
-                o = attention_prefill(q, k, v, causal=True, window=window,
-                                      q_block=q_block, k_block=k_block,
-                                      kv_valid=vl)
+            with jax.named_scope("attention"):
+                if cp:
+                    o = _cp_attention(q, k, v, window)
+                else:
+                    o = attention_prefill(q, k, v, causal=True, window=window,
+                                          q_block=q_block, k_block=k_block,
+                                          kv_valid=vl)
             x = x + shard(o.reshape(B, S, nq * hd) @ attn_p["wo"],
                           "batch", "seq", "embed")
             h2 = rmsnorm(x, lp["ln2"], eps)
-            y, _ = _ffn(lp, h2)
+            with jax.named_scope("mlp"):
+                y, _ = _ffn(lp, h2)
             x = x + shard(y, "batch", "seq", "embed")
             if Smax > S:
                 pad = [(0, 0), (0, Smax - S), (0, 0), (0, 0)]
@@ -205,7 +207,9 @@ def build(cfg: ModelConfig, mesh, rules, *, remat: str = "full",
                       if len(set(static_windows)) == 1 else None)
 
     def decode_step(params, cache, tokens, lengths):
-        """tokens: [B,1]; lengths: [B] current context length per sample."""
+        """tokens: [B,1]; lengths: [B] current context length per sample.
+        Named scopes (``attention``, ``kv_update``, ``mlp``, ``lm_head``)
+        tie the compiled program's ops to the phase they came from."""
         x = embed_tokens(params["embed"], tokens)
         B = x.shape[0]
 
@@ -215,20 +219,25 @@ def build(cfg: ModelConfig, mesh, rules, *, remat: str = "full",
             q, k, v = attn_qkv(lp["attn"], h, nq, nkv, hd)
             q = rope(q, lengths[:, None], cfg.rope_theta)
             k = rope(k, lengths[:, None], cfg.rope_theta)
-            k_l, v_l = cache_update(k_l, v_l, k, v, lengths)
-            o = attention_decode_auto(
-                q, k_l, v_l, lengths + 1,
-                window=window if uniform_window is None else uniform_window)
+            with jax.named_scope("kv_update"):
+                k_l, v_l = cache_update(k_l, v_l, k, v, lengths)
+            with jax.named_scope("attention"):
+                o = attention_decode_auto(
+                    q, k_l, v_l, lengths + 1,
+                    window=window if uniform_window is None
+                    else uniform_window)
             x = x + shard(o.reshape(B, 1, nq * hd) @ lp["attn"]["wo"],
                           "batch", None, "embed")
             h2 = rmsnorm(x, lp["ln2"], eps)
-            y, _ = _ffn(lp, h2)
+            with jax.named_scope("mlp"):
+                y, _ = _ffn(lp, h2)
             x = x + shard(y, "batch", None, "embed")
             return x, (k_l, v_l)
 
         x, (ks, vs) = lax.scan(body, x,
                                (params["layers"], windows, cache["k"], cache["v"]))
-        logits = lm_head(params["embed"], x, eps)[:, 0]
+        with jax.named_scope("lm_head"):
+            logits = lm_head(params["embed"], x, eps)[:, 0]
         new_cache = {"k": ks, "v": vs, "lengths": lengths + 1}
         return logits, new_cache
 

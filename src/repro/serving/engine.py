@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.batching import AIMDController, bucket, prompt_length_ladder
@@ -67,13 +68,17 @@ class Request:
     tokens: List[int] = field(default_factory=list)
     done: bool = False
     slot: Optional[int] = None
-    prefill_time: Optional[float] = None
     finish_time: Optional[float] = None
-    # span tracing (repro.obs): the request's root span, plus the exact
-    # phase boundaries latency attribution partitions the SLO budget along
-    trace: Optional[Any] = None
+    # phase boundaries on the engine clock, stamped with or without a
+    # tracer: latency attribution partitions the SLO budget along them and
+    # engine_report() reads TTFT and inter-token gaps from them
     dispatch_time: Optional[float] = None     # left the queue for prefill
     prefill_end: Optional[float] = None       # prefill done, decode begins
+    # one stamp per token: the engine clock right after the host transfer
+    # that delivered it
+    token_times: List[float] = field(default_factory=list)
+    # span tracing (repro.obs): the request's root span
+    trace: Optional[Any] = None
     # injected per-request failure (repro.faults.RequestFaults): the tokens
     # exist but the answer is unusable — downstream policy (LMCascade)
     # escalates failed drafts and degrades failed verifies
@@ -95,21 +100,22 @@ def make_fused_decode_fn(model: Model, mesh, rules, *, temperature: float,
     def fused(params, cache, lengths, cur, active, gen, max_new, key):
         with sharding_context(mesh, rules):
             logits, cache = model.decode_step(params, cache, cur, lengths)
-        toks = sample(logits, key, temperature=temperature)
-        act = active.astype(jnp.int32)
-        new_len = lengths + act
-        new_cur = jnp.where(active[:, None], toks[:, None], cur)
-        new_gen = gen + act
-        done = active & ((toks == eos) | (new_gen >= max_new)
-                         | (new_len >= max_len - 1))
-        packed = jnp.concatenate([toks.astype(jnp.int32),
-                                  done.astype(jnp.int32)])
+        with jax.named_scope("sample"):
+            toks = sample(logits, key, temperature=temperature)
+            act = active.astype(jnp.int32)
+            new_len = lengths + act
+            new_cur = jnp.where(active[:, None], toks[:, None], cur)
+            new_gen = gen + act
+            done = active & ((toks == eos) | (new_gen >= max_new)
+                             | (new_len >= max_len - 1))
+            packed = jnp.concatenate([toks.astype(jnp.int32),
+                                      done.astype(jnp.int32)])
         return packed, cache, new_len, new_cur, active & ~done, new_gen
 
     return fused
 
 
-def batched_scatter(cache, pcache, slot_mask, src_idx):
+def admit_scatter(cache, pcache, slot_mask, src_idx):
     """Scatter a whole prefilled batch into the slot cache in one shot.
 
     ``slot_mask``: [slots] bool — slots receiving a new request;
@@ -134,8 +140,8 @@ def batched_scatter(cache, pcache, slot_mask, src_idx):
     return jax.tree.map(leaf, cache, pcache)
 
 
-def _admit_state(lengths, cur, active, gen, max_new, slot_mask, src_idx,
-                 vlens, firsts, maxnews):
+def admit_state(lengths, cur, active, gen, max_new, slot_mask, src_idx,
+                vlens, firsts, maxnews):
     """Batched slot-state update at admission (device-resident mirror of the
     per-request bookkeeping): one dispatch for the whole admitted batch."""
     new_len = jnp.where(slot_mask, vlens[src_idx], lengths)
@@ -248,8 +254,8 @@ class LMServer:
                                      temperature=temperature, eos=eos_token,
                                      max_len=max_len),
                 donate_argnums=(1, 2, 3, 4, 5))
-            self._scatter_jit = jax.jit(batched_scatter, donate_argnums=(0,))
-            self._admit_state_jit = jax.jit(_admit_state,
+            self._scatter_jit = jax.jit(admit_scatter, donate_argnums=(0,))
+            self._admit_state_jit = jax.jit(admit_state,
                                             donate_argnums=(0, 1, 2, 3, 4))
         else:
             def decode_fn(params, cache, tokens, lengths, key):
@@ -374,32 +380,44 @@ class LMServer:
         free = [s for s in range(self.slots) if s not in self._active]
         if not free or not self._queue:
             return
-        n = min(len(free), len(self._queue), self.admission.max_batch_size)
-        batch, padded = self._take_batch(n)
-        n = len(batch)
-        if n == 0:
-            return
-        self.metrics.observe(M.QUEUE_DEPTH, n + len(self._queue))
-        if padded:
-            plen = bucket(max(len(r.prompt) for r in batch),
-                          ladder=self.length_ladder)
-        else:
-            plen = len(batch[0].prompt)
-        nb = bucket(n, cap=self.slots)
-        toks = np.zeros((nb, plen), np.int32)
-        vlens = np.full((nb,), plen, np.int32)
-        for i, r in enumerate(batch):
-            L = len(r.prompt)
-            toks[i, :L] = r.prompt
-            vlens[i] = L
+        with TraceAnnotation("lm.step.admit"):
+            self._admit_batch(params, free)
+
+    def _admit_batch(self, params, free: List[int]) -> None:
+        """Prefill one batch from the queue head and park it in ``free``
+        slots. The ``lm.step.*`` spans mark its phases on the profiler's
+        clock; those named ``wait_*`` are where the host blocks on the
+        device."""
+        with TraceAnnotation("lm.step.batch"):
+            n = min(len(free), len(self._queue),
+                    self.admission.max_batch_size)
+            batch, padded = self._take_batch(n)
+            n = len(batch)
+            if n == 0:
+                return
+            self.metrics.observe(M.QUEUE_DEPTH, n + len(self._queue))
+            if padded:
+                plen = bucket(max(len(r.prompt) for r in batch),
+                              ladder=self.length_ladder)
+            else:
+                plen = len(batch[0].prompt)
+            nb = bucket(n, cap=self.slots)
+            toks = np.zeros((nb, plen), np.int32)
+            vlens = np.full((nb,), plen, np.int32)
+            for i, r in enumerate(batch):
+                L = len(r.prompt)
+                toks[i, :L] = r.prompt
+                vlens[i] = L
         t0 = self.clock()
-        if padded:
-            logits, pcache = self._prefill_jit(nb, plen, True)(
-                params, jnp.asarray(toks), jnp.asarray(vlens))
-        else:
-            logits, pcache = self._prefill_jit(nb, plen, False)(
-                params, jnp.asarray(toks))
-        jax.block_until_ready(logits)
+        with TraceAnnotation("lm.step.prefill"):
+            if padded:
+                logits, pcache = self._prefill_jit(nb, plen, True)(
+                    params, jnp.asarray(toks), jnp.asarray(vlens))
+            else:
+                logits, pcache = self._prefill_jit(nb, plen, False)(
+                    params, jnp.asarray(toks))
+        with TraceAnnotation("lm.step.wait_prefill"):
+            jax.block_until_ready(logits)
         self.prefill_dispatches += 1
         self.rung_dispatches[int(plen)] = (
             self.rung_dispatches.get(int(plen), 0) + 1)
@@ -410,92 +428,96 @@ class LMServer:
         self.metrics.inc(M.QUERIES_SUBMITTED, n, model=self.model_id)
         self._observe_batch(n, dt)
         self.metrics.mark(self.clock())
-        if self.tracer is not None:
-            # queue span: arrival -> dispatch; prefill span: the batch's
-            # service interval, budgeted at the prefill share of the SLO
-            for r in batch:
-                r.dispatch_time = t0
-                r.prefill_end = t0 + dt
-                if r.trace is not None:
-                    self.tracer.add_span(r.trace, "queue", "lm.queue",
-                                         r.arrival_time, t0)
-                    self.tracer.add_span(
-                        r.trace, "prefill", "lm.prefill", t0, t0 + dt,
-                        budget_s=self.slo * self.prefill_slo_frac,
-                        attrs={"batch": n, "padded_len": int(plen)})
+        for r in batch:
+            r.dispatch_time = t0
+            r.prefill_end = t0 + dt
+            if self.tracer is not None and r.trace is not None:
+                # queue span: arrival -> dispatch; prefill span: the batch's
+                # service interval, budgeted at the prefill share of the SLO
+                self.tracer.add_span(r.trace, "queue", "lm.queue",
+                                     r.arrival_time, t0)
+                self.tracer.add_span(
+                    r.trace, "prefill", "lm.prefill", t0, t0 + dt,
+                    budget_s=self.slo * self.prefill_slo_frac,
+                    attrs={"batch": n, "padded_len": int(plen)})
         self.rng, k = jax.random.split(self.rng)
-        first = sample(logits, k, temperature=self.temperature)
-        first_np = np.asarray(first)
-        if self.fused:
-            # one jitted scatter + one jitted state update for the whole
-            # admitted batch (the donated cache is rematerialized once, not
-            # once per request)
-            slot_mask = np.zeros((self.slots,), bool)
-            src_idx = np.zeros((self.slots,), np.int32)
-            maxnews = np.zeros((nb,), np.int32)
+        with TraceAnnotation("lm.step.wait_first_token"):
+            first = sample(logits, k, temperature=self.temperature)
+            first_np = np.asarray(first)
+        now = self.clock()
+        with TraceAnnotation("lm.step.scatter"):
             for i, r in enumerate(batch):
-                s = free[i]
-                r.slot = s
-                r.prefill_time = dt
+                r.slot = free[i]
                 r.tokens.append(int(first_np[i]))
-                self._active[s] = r
-                slot_mask[s] = True
-                src_idx[s] = i
-                maxnews[i] = r.max_new_tokens
-            slot_mask = jnp.asarray(slot_mask)
-            src_idx = jnp.asarray(src_idx)
-            self.cache = self._scatter_jit(self.cache, pcache, slot_mask,
-                                           src_idx)
-            (self.lengths, self.cur_tokens, self.active_mask,
-             self.gen_counts, self.max_new) = self._admit_state_jit(
-                self.lengths, self.cur_tokens, self.active_mask,
-                self.gen_counts, self.max_new, slot_mask, src_idx,
-                jnp.asarray(vlens), first.astype(jnp.int32),
-                jnp.asarray(maxnews))
-        else:
-            # reference path: per-request scatter, per-slot host bookkeeping
-            for i, r in enumerate(batch):
-                s = free[i]
-                r.slot = s
-                r.prefill_time = dt
-                r.tokens.append(int(first_np[i]))
-                self._active[s] = r
-                self.cache = _scatter_cache(self.cache, pcache, i, s)
-                self.lengths = self.lengths.at[s].set(int(vlens[i]))
-                self.cur_tokens = self.cur_tokens.at[s, 0].set(
-                    int(first_np[i]))
+                r.token_times.append(now)
+                self._active[free[i]] = r
+            if self.fused:
+                # one jitted scatter + one jitted state update for the whole
+                # admitted batch (the donated cache is rematerialized once,
+                # not once per request)
+                slot_mask = np.zeros((self.slots,), bool)
+                src_idx = np.zeros((self.slots,), np.int32)
+                maxnews = np.zeros((nb,), np.int32)
+                for i, r in enumerate(batch):
+                    slot_mask[free[i]] = True
+                    src_idx[free[i]] = i
+                    maxnews[i] = r.max_new_tokens
+                slot_mask = jnp.asarray(slot_mask)
+                src_idx = jnp.asarray(src_idx)
+                self.cache = self._scatter_jit(self.cache, pcache, slot_mask,
+                                               src_idx)
+                (self.lengths, self.cur_tokens, self.active_mask,
+                 self.gen_counts, self.max_new) = self._admit_state_jit(
+                    self.lengths, self.cur_tokens, self.active_mask,
+                    self.gen_counts, self.max_new, slot_mask, src_idx,
+                    jnp.asarray(vlens), first.astype(jnp.int32),
+                    jnp.asarray(maxnews))
+            else:
+                # reference path: per-request scatter, per-slot host
+                # bookkeeping
+                for i in range(n):
+                    s = free[i]
+                    self.cache = _scatter_cache(self.cache, pcache, i, s)
+                    self.lengths = self.lengths.at[s].set(int(vlens[i]))
+                    self.cur_tokens = self.cur_tokens.at[s, 0].set(
+                        int(first_np[i]))
 
     def _decode_once(self, params) -> None:
         if not self._active:
             return
-        if self.fused:
-            self._decode_once_fused(params)
-        else:
-            self._decode_once_reference(params)
+        with TraceAnnotation("lm.step.decode"):
+            if self.fused:
+                self._decode_once_fused(params)
+            else:
+                self._decode_once_reference(params)
 
     def _decode_once_fused(self, params) -> None:
         t0 = self.clock()
-        self.rng, k = jax.random.split(self.rng)
-        args = (params, self.cache, self.lengths, self.cur_tokens,
-                self.active_mask, self.gen_counts, self.max_new, k)
-        self._note_backend(self._decode_fused, args)
-        (packed, self.cache, self.lengths, self.cur_tokens,
-         self.active_mask, self.gen_counts) = self._decode_fused(*args)
-        out = np.asarray(packed)            # the ONE host transfer per step
-        self.decode_host_syncs += 1
-        toks, done = out[:self.slots], out[self.slots:].astype(bool)
-        n_active = len(self._active)
+        with TraceAnnotation("lm.step.dispatch"):
+            self.rng, k = jax.random.split(self.rng)
+            args = (params, self.cache, self.lengths, self.cur_tokens,
+                    self.active_mask, self.gen_counts, self.max_new, k)
+            self._note_backend(self._decode_fused, args)
+            (packed, self.cache, self.lengths, self.cur_tokens,
+             self.active_mask, self.gen_counts) = self._decode_fused(*args)
+        with TraceAnnotation("lm.step.wait_decode"):
+            out = np.asarray(packed)        # the ONE host transfer per step
         # executed shape: the jitted decode computes every slot each step
         # regardless of how many are active, like the wall-clock engine
         dt = self._service_time("decode", self.slots, 1, t0)
+        now = self.clock()
+        self.decode_host_syncs += 1
+        toks, done = out[:self.slots], out[self.slots:].astype(bool)
         # decode steps dominate LM serving work — they count as dispatched
         # batches alongside prefill, so the report reflects the whole run
-        self._observe_batch(n_active, dt)
+        self._observe_batch(len(self._active), dt)
         self.decode_steps += 1
-        for s, r in list(self._active.items()):
-            r.tokens.append(int(toks[s]))
-            if done[s]:
-                self._finish(s, r)
+        with TraceAnnotation("lm.step.tokens"):
+            for s, r in list(self._active.items()):
+                r.tokens.append(int(toks[s]))
+                r.token_times.append(now)
+                if done[s]:
+                    self._finish(s, r)
 
     def _decode_once_reference(self, params) -> None:
         """PR-3 hot path, kept verbatim as the parity/benchmark baseline:
@@ -507,10 +529,10 @@ class LMServer:
         self._note_backend(self._decode, args)
         toks, self.cache = self._decode(*args)
         toks = np.asarray(toks)
-        self.decode_host_syncs += 1
-        n_active = len(self._active)
         dt = self._service_time("decode", self.slots, 1, t0)
-        self._observe_batch(n_active, dt)
+        now = self.clock()
+        self.decode_host_syncs += 1
+        self._observe_batch(len(self._active), dt)
         self.decode_steps += 1
         self.lengths = self.lengths + jnp.asarray(
             [1 if s in self._active else 0 for s in range(self.slots)],
@@ -518,6 +540,7 @@ class LMServer:
         for s, r in list(self._active.items()):
             t = int(toks[s])
             r.tokens.append(t)
+            r.token_times.append(now)
             self.cur_tokens = self.cur_tokens.at[s, 0].set(t)
             cur_len = int(self.lengths[s])
             self.decode_host_syncs += 1     # per-slot device read
@@ -621,8 +644,9 @@ class LMServer:
         return out
 
     def step(self, params) -> None:
-        self._admit(params)
-        self._decode_once(params)
+        with TraceAnnotation("lm.step"):
+            self._admit(params)
+            self._decode_once(params)
 
     def run(self, params, *, max_steps: int = 10_000) -> None:
         steps = 0
@@ -648,8 +672,17 @@ class LMServer:
     def engine_report(self) -> Dict[str, Any]:
         """Engine-level observability counters (DESIGN.md §11 hot path):
         where XLA compiles happened, how chatty the decode loop is with the
-        host, and which attention backend the decode step traced with."""
+        host, and which attention backend the decode step traced with; and,
+        from the completed requests' token stamps, time to first token
+        (first token minus arrival) and the gaps between tokens."""
+        done = list(self.completed.values())
+        ttft = [r.token_times[0] - r.arrival_time for r in done
+                if r.token_times]
+        tpot = [b - a for r in done
+                for a, b in zip(r.token_times, r.token_times[1:])]
         return {
+            "ttft_s": _percentiles(ttft),
+            "tpot_s": _percentiles(tpot),
             "fused": self.fused,
             # what the decode step traced, not the requested switch; None
             # until the first decode step
@@ -688,6 +721,13 @@ class LMServer:
         rep = self.report()
         rep.update(extra)
         return json.dumps(rep, sort_keys=True, indent=2)
+
+
+def _percentiles(values: List[float]) -> Dict[str, Any]:
+    if not values:
+        return {"p50": None, "p95": None, "count": 0}
+    p50, p95 = np.percentile(np.asarray(values, np.float64), [50, 95])
+    return {"p50": float(p50), "p95": float(p95), "count": len(values)}
 
 
 def _scatter_cache(cache, pcache, src: int, dst: int):

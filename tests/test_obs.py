@@ -216,7 +216,10 @@ def test_tracing_off_by_default_adds_no_report_sections():
 def test_lm_report_always_carries_engine_section():
     rep = ScenarioRunner(Scenario("t", **_LM)).run("lmserver")
     eng = rep["engine"]
-    assert set(eng) == {"fused", "attention_backend", "prefill", "decode"}
+    assert set(eng) == {"fused", "attention_backend", "prefill", "decode",
+                        "ttft_s", "tpot_s"}
+    assert eng["ttft_s"]["count"] >= 1 and eng["ttft_s"]["p95"] >= 0
+    assert eng["tpot_s"]["count"] >= 1
     assert eng["prefill"]["dispatches"] >= 1
     assert eng["prefill"]["compiled_shapes"] == len(eng["prefill"]["shapes"])
     assert eng["decode"]["steps"] >= 1

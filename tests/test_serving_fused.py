@@ -17,7 +17,7 @@ from repro.models.api import build_model
 from repro.models.common import (attention_decode, attention_decode_auto,
                                  get_attention_backend,
                                  set_attention_backend)
-from repro.serving.engine import (LMServer, _scatter_cache, batched_scatter,
+from repro.serving.engine import (LMServer, _scatter_cache, admit_scatter,
                                   make_fused_decode_fn)
 from repro.serving.sampler import sample
 
@@ -143,7 +143,7 @@ def test_fused_step_token_parity_all_families(built, mesh, fam):
         cache = model.init_cache(slots, max_len)
         mask = jnp.asarray([True, True, False])
         src = jnp.asarray([0, 1, 0], jnp.int32)
-        return batched_scatter(cache, pcache, mask, src)
+        return admit_scatter(cache, pcache, mask, src)
 
     lengths0 = jnp.asarray([plen, plen, 0], jnp.int32)
     cur0 = jnp.asarray([[first[0]], [first[1]], [0]], jnp.int32)
@@ -215,7 +215,7 @@ def test_batched_scatter_matches_reference(built, mesh, fam):
     ref = model.init_cache(slots, max_len)
     ref = _scatter_cache(ref, pcache, 0, 2)
     ref = _scatter_cache(ref, pcache, 1, 0)
-    got = batched_scatter(model.init_cache(slots, max_len), pcache,
+    got = admit_scatter(model.init_cache(slots, max_len), pcache,
                           jnp.asarray([True, False, True, False]),
                           jnp.asarray([1, 0, 0, 0], jnp.int32))
     for rl, gl in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
