@@ -11,9 +11,10 @@ sampling, per-slot length advance, EOS/max-token done-masking, and the
 next-token feedback into the one jitted function — the host sees exactly one
 compact ``[tokens ‖ done]`` transfer per step (O(1) in slots, down from the
 O(slots) per-step syncs of the reference loop, kept here as ``fused=False``
-for parity tests and before/after benchmarks). Admission likewise scatters
+for parity tests and before/after benchmarks). Admission likewise writes
 the whole prefilled batch into the donated slot cache with one jitted
-masked-select, and prompts are padded up a geometric *length ladder*
+program that updates only the admitted rows, in place, and prompts are
+padded up a geometric *length ladder*
 (``core.batching.prompt_length_ladder``) so distinct prefill compilations
 are bounded by the ladder, not by the workload's distinct prompt lengths —
 and mixed-length traces no longer head-of-line block behind same-length
@@ -38,6 +39,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
@@ -115,29 +117,34 @@ def make_fused_decode_fn(model: Model, mesh, rules, *, temperature: float,
     return fused
 
 
-def admit_scatter(cache, pcache, slot_mask, src_idx):
-    """Scatter a whole prefilled batch into the slot cache in one shot.
+def admit_scatter(cache, pcache, dst, n):
+    """Write the first ``n`` rows of a prefilled batch into their slots of
+    the (donated) slot cache, in place.
 
-    ``slot_mask``: [slots] bool — slots receiving a new request;
-    ``src_idx``: [slots] int32 — row of ``pcache`` for each receiving slot
-    (arbitrary where the mask is False). Implemented as gather + masked
-    select per leaf, so the donated cache is rematerialized once for the
-    whole admitted batch instead of once per request (`_scatter_cache`).
+    ``dst``: [nb] int32 — destination slot of each row of ``pcache``; rows
+    ``n..nb-1`` pad the batch rung and are never written (the loop runs over
+    the traced ``n``, since a clamped out-of-range start would land on the
+    last slot). Each admitted row is one dynamic-update-slice per leaf, so
+    an admission reads and writes its own rows and no other slot's bytes.
     Leaves are [B] (lengths), or layer-stacked [L, B, ...] with an optional
     shorter dim-2 (e.g. encoder memory) padded up to the destination."""
 
-    def leaf(dv, sv):
+    def leaf(dv, sv, i, s):
         if dv.ndim == 1:
-            return jnp.where(slot_mask, sv[src_idx].astype(dv.dtype), dv)
-        sl = jnp.take(sv, src_idx, axis=1)
-        if sl.ndim > 2 and sl.shape[2] < dv.shape[2]:
-            pad = dv.shape[2] - sl.shape[2]
-            sl = jnp.pad(sl, [(0, 0), (0, 0), (0, pad)]
-                         + [(0, 0)] * (sl.ndim - 3))
-        m = slot_mask.reshape((1, slot_mask.shape[0]) + (1,) * (sl.ndim - 2))
-        return jnp.where(m, sl.astype(dv.dtype), dv)
+            return lax.dynamic_update_slice(
+                dv, lax.dynamic_slice(sv, (i,), (1,)).astype(dv.dtype), (s,))
+        row = lax.dynamic_slice_in_dim(sv, i, 1, axis=1)
+        if row.ndim > 2 and row.shape[2] < dv.shape[2]:
+            pad = dv.shape[2] - row.shape[2]
+            row = jnp.pad(row, [(0, 0), (0, 0), (0, pad)]
+                          + [(0, 0)] * (row.ndim - 3))
+        start = (0, s) + (0,) * (dv.ndim - 2)
+        return lax.dynamic_update_slice(dv, row.astype(dv.dtype), start)
 
-    return jax.tree.map(leaf, cache, pcache)
+    def body(i, c):
+        return jax.tree.map(lambda dv, sv: leaf(dv, sv, i, dst[i]), c, pcache)
+
+    return lax.fori_loop(0, n, body, cache)
 
 
 def admit_state(lengths, cur, active, gen, max_new, slot_mask, src_idx,
@@ -229,6 +236,9 @@ class LMServer:
         self.decode_steps = 0
         self.decode_host_syncs = 0
         self.prefill_dispatches = 0
+        # slot-cache rows admission wrote: one per admitted request, never
+        # the whole slot count per dispatch
+        self.admit_rows = 0
         # prefill dispatches per ladder rung (padded prompt length) — which
         # rungs the workload actually exercises (repro.obs.timeseries)
         self.rung_dispatches: Dict[int, int] = {}
@@ -453,19 +463,22 @@ class LMServer:
                 self._active[free[i]] = r
             if self.fused:
                 # one jitted scatter + one jitted state update for the whole
-                # admitted batch (the donated cache is rematerialized once,
-                # not once per request)
+                # admitted batch; the scatter writes the n admitted rows into
+                # the donated cache in place and leaves every other slot be
                 slot_mask = np.zeros((self.slots,), bool)
                 src_idx = np.zeros((self.slots,), np.int32)
+                dst = np.full((nb,), self.slots, np.int32)
                 maxnews = np.zeros((nb,), np.int32)
                 for i, r in enumerate(batch):
                     slot_mask[free[i]] = True
                     src_idx[free[i]] = i
+                    dst[i] = free[i]
                     maxnews[i] = r.max_new_tokens
                 slot_mask = jnp.asarray(slot_mask)
                 src_idx = jnp.asarray(src_idx)
-                self.cache = self._scatter_jit(self.cache, pcache, slot_mask,
-                                               src_idx)
+                self.cache = self._scatter_jit(self.cache, pcache,
+                                               jnp.asarray(dst), np.int32(n))
+                self.admit_rows += n
                 (self.lengths, self.cur_tokens, self.active_mask,
                  self.gen_counts, self.max_new) = self._admit_state_jit(
                     self.lengths, self.cur_tokens, self.active_mask,
@@ -478,6 +491,7 @@ class LMServer:
                 for i in range(n):
                     s = free[i]
                     self.cache = _scatter_cache(self.cache, pcache, i, s)
+                    self.admit_rows += 1
                     self.lengths = self.lengths.at[s].set(int(vlens[i]))
                     self.cur_tokens = self.cur_tokens.at[s, 0].set(
                         int(first_np[i]))
@@ -667,6 +681,7 @@ class LMServer:
                 if self.decode_steps else 0.0),
             "prefill_compiles": self.prefill_compiles,
             "prefill_dispatches": self.prefill_dispatches,
+            "admit_rows": self.admit_rows,
         }
 
     def engine_report(self) -> Dict[str, Any]:
@@ -732,7 +747,7 @@ def _percentiles(values: List[float]) -> Dict[str, Any]:
 
 def _scatter_cache(cache, pcache, src: int, dst: int):
     """Copy request ``src`` of a prefill cache into slot ``dst`` (reference
-    per-request path; the fused engine uses :func:`batched_scatter`)."""
+    per-request path; the fused engine uses :func:`admit_scatter`)."""
     out = {}
     for k, v in cache.items():
         pv = pcache[k]

@@ -90,7 +90,7 @@ def test_admission_programs_carry_their_names(served, mesh):
     mask = jnp.zeros((4,), bool)
     idx = jnp.zeros((4,), jnp.int32)
     rows = jnp.zeros((2,), jnp.int32)
-    scatter = srv._scatter_jit.lower(srv.cache, pcache, mask, idx)
+    scatter = srv._scatter_jit.lower(srv.cache, pcache, rows, np.int32(2))
     state = srv._admit_state_jit.lower(
         srv.lengths, srv.cur_tokens, srv.active_mask, srv.gen_counts,
         srv.max_new, mask, idx, rows, rows, rows)

@@ -141,9 +141,8 @@ def test_fused_step_token_parity_all_families(built, mesh, fam):
 
     def scattered():
         cache = model.init_cache(slots, max_len)
-        mask = jnp.asarray([True, True, False])
-        src = jnp.asarray([0, 1, 0], jnp.int32)
-        return admit_scatter(cache, pcache, mask, src)
+        return admit_scatter(cache, pcache, jnp.asarray([0, 1], jnp.int32),
+                             2)
 
     lengths0 = jnp.asarray([plen, plen, 0], jnp.int32)
     cur0 = jnp.asarray([[first[0]], [first[1]], [0]], jnp.int32)
@@ -199,28 +198,117 @@ def test_fused_step_token_parity_all_families(built, mesh, fam):
 # batched scatter == per-request reference scatter
 # ---------------------------------------------------------------------------
 
+def _gather_select(cache, pcache, slot_mask, src_idx):
+    """The batched scatter as it was first written: gather every slot's
+    source row, then a masked select over the whole cache (the oracle)."""
+
+    def leaf(dv, sv):
+        if dv.ndim == 1:
+            return jnp.where(slot_mask, sv[src_idx].astype(dv.dtype), dv)
+        sl = jnp.take(sv, src_idx, axis=1)
+        if sl.ndim > 2 and sl.shape[2] < dv.shape[2]:
+            pad = dv.shape[2] - sl.shape[2]
+            sl = jnp.pad(sl, [(0, 0), (0, 0), (0, pad)]
+                         + [(0, 0)] * (sl.ndim - 3))
+        m = slot_mask.reshape((1, slot_mask.shape[0]) + (1,) * (sl.ndim - 2))
+        return jnp.where(m, sl.astype(dv.dtype), dv)
+
+    return jax.tree.map(leaf, cache, pcache)
+
+
+def _filled_cache(model, slots, max_len, seed):
+    """A slot cache of non-zero random values: a slot the scatter leaves
+    alone is told apart from one it zeroes."""
+    rng = np.random.default_rng(seed)
+
+    def fill(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jnp.asarray(rng.integers(1, 1000, a.shape), a.dtype)
+        return jnp.asarray(rng.normal(size=a.shape) + 3.0, a.dtype)
+
+    return jax.tree.map(fill, model.init_cache(slots, max_len))
+
+
+# (prefill rows nb, admitted n, destination slot of each admitted row);
+# rows n..nb-1 pad the rung and aim at slot index ``slots``, which a clamped
+# dynamic-update-slice would turn into the last slot
+SCATTER_CASES = {
+    "full_rung": (2, 2, [2, 0]),
+    "pad_rows": (4, 2, [1, 2]),
+    "last_slot": (2, 1, [3]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCATTER_CASES))
 @pytest.mark.parametrize("fam", sorted(FAMILIES))
-def test_batched_scatter_matches_reference(built, mesh, fam):
+def test_batched_scatter_matches_reference(built, mesh, fam, case):
+    """The in-place row scatter writes exactly what the gather-select
+    wrote, bit for bit, into the admitted slots, and leaves every other
+    slot (pad rows' targets included) as it was."""
     cfg, model, params = built[fam]
+    nb, n, dsts = SCATTER_CASES[case]
     rng = np.random.default_rng(4)
     slots, max_len, plen = 4, 32, 6
-    toks = rng.integers(0, cfg.vocab_size, (2, plen)).astype(np.int32)
+    toks = rng.integers(0, cfg.vocab_size, (nb, plen)).astype(np.int32)
     batch = {"tokens": jnp.asarray(toks)}
     if cfg.family == "encdec":
         batch["frames"] = jnp.asarray(
-            rng.normal(size=(2, 8, cfg.d_model)) * 0.02, jnp.float32)
+            rng.normal(size=(nb, 8, cfg.d_model)) * 0.02, jnp.float32)
     _, pcache = model.prefill(params, batch, max_len=max_len)
 
-    # request 0 -> slot 2, request 1 -> slot 0
-    ref = model.init_cache(slots, max_len)
-    ref = _scatter_cache(ref, pcache, 0, 2)
-    ref = _scatter_cache(ref, pcache, 1, 0)
-    got = admit_scatter(model.init_cache(slots, max_len), pcache,
-                          jnp.asarray([True, False, True, False]),
-                          jnp.asarray([1, 0, 0, 0], jnp.int32))
-    for rl, gl in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
-        np.testing.assert_array_equal(np.asarray(rl, np.float32),
-                                      np.asarray(gl, np.float32))
+    slot_mask = np.zeros((slots,), bool)
+    src_idx = np.zeros((slots,), np.int32)
+    dst = np.full((nb,), slots, np.int32)
+    for i, s in enumerate(dsts):
+        slot_mask[s], src_idx[s], dst[i] = True, i, s
+    before = _filled_cache(model, slots, max_len, 7)
+    oracle = _gather_select(before, pcache, jnp.asarray(slot_mask),
+                            jnp.asarray(src_idx))
+    ref = before
+    for i, s in enumerate(dsts):
+        ref = _scatter_cache(ref, pcache, i, s)
+    got = jax.jit(admit_scatter, donate_argnums=(0,))(
+        _filled_cache(model, slots, max_len, 7), pcache, jnp.asarray(dst),
+        np.int32(n))
+    for ol, rl, gl, bl in zip(jax.tree.leaves(oracle), jax.tree.leaves(ref),
+                              jax.tree.leaves(got), jax.tree.leaves(before)):
+        assert gl.dtype == ol.dtype and gl.shape == ol.shape
+        g = np.asarray(gl, np.float32)
+        np.testing.assert_array_equal(np.asarray(ol, np.float32), g)
+        np.testing.assert_array_equal(np.asarray(rl, np.float32), g)
+        kept = [s for s in range(slots) if s not in dsts]
+        b = np.asarray(bl, np.float32)
+        axis = 1 if bl.ndim > 1 else 0
+        np.testing.assert_array_equal(np.take(b, kept, axis=axis),
+                                      np.take(g, kept, axis=axis))
+
+
+def test_reused_slot_matches_reference(built, mesh):
+    """A long request finishes; a shorter one lands in the slot it freed
+    while another slot still decodes. Greedy tokens equal the reference
+    engine's, and admission wrote one cache row per admitted request."""
+    cfg, model, params = built["dense"]
+    rng = np.random.default_rng(8)
+    long_p, live_p, short_p = (rng.integers(0, cfg.vocab_size, size=k)
+                               for k in (40, 8, 5))
+    streams = {}
+    for fused in (True, False):
+        srv = _sim_server(model, mesh, slots=2, fused=fused)
+        a = srv.submit(long_p, max_new_tokens=3)
+        b = srv.submit(live_p, max_new_tokens=16)
+        while a not in srv.completed:
+            srv.step(params)
+        assert b not in srv.completed
+        freed = srv.completed[a].slot
+        c = srv.submit(short_p, max_new_tokens=5)
+        srv.step(params)
+        assert srv._active[freed].request_id == c
+        assert len(srv._active) == 2
+        srv.run(params)
+        assert srv.stats["admit_rows"] == 3
+        streams[fused] = [srv.completed[r].tokens for r in (a, b, c)]
+    assert streams[True] == streams[False]
+    assert [len(t) for t in streams[True]] == [3, 16, 5]
 
 
 # ---------------------------------------------------------------------------
